@@ -10,7 +10,6 @@ import time
 import jax
 
 from repro.kernels import ref
-from repro.kernels.ops import sjlt_apply
 from .common import emit
 
 
@@ -38,8 +37,7 @@ def run():
         rows_i = jax.random.randint(jax.random.PRNGKey(2), (n,), 0, m)
         signs = jax.random.rademacher(jax.random.PRNGKey(3), (n,),
                                       dtype=A.dtype)
-        fn = jax.jit(lambda A, r, s: sjlt_apply(A, r, s, m,
-                                                use_pallas=False))
+        fn = jax.jit(lambda A, r, s: ref.sjlt_ref(A, r, s, m))
         dt = _time(fn, A, rows_i, signs)
         rows.append(dict(bench="sjlt_ref", n=n, d=d, m=m,
                          us_per_call=round(dt * 1e6, 1),

@@ -16,6 +16,8 @@ import dataclasses
 from typing import Callable, Iterable, Iterator
 
 import jax
+import jax.extend.core as jex_core
+from jax._src import source_info_util
 import numpy as np
 
 
@@ -23,15 +25,15 @@ def subjaxprs(eqn) -> Iterable:
     """Every sub-jaxpr referenced by an equation's params (scan/while/cond
     bodies, pjit calls, shard_map, custom_* wrappers)."""
     for v in eqn.params.values():
-        if isinstance(v, jax.core.ClosedJaxpr):
+        if isinstance(v, jex_core.ClosedJaxpr):
             yield v.jaxpr
-        elif isinstance(v, jax.core.Jaxpr):
+        elif isinstance(v, jex_core.Jaxpr):
             yield v
         elif isinstance(v, (tuple, list)):
             for item in v:
-                if isinstance(item, jax.core.ClosedJaxpr):
+                if isinstance(item, jex_core.ClosedJaxpr):
                     yield item.jaxpr
-                elif isinstance(item, jax.core.Jaxpr):
+                elif isinstance(item, jex_core.Jaxpr):
                     yield item
 
 
@@ -208,14 +210,10 @@ def eqn_provenance(eqn) -> str:
     equation — what makes a violation actionable."""
     name = getattr(getattr(eqn, "primitive", None), "name", "?")
     src = getattr(eqn, "source_info", None)
-    try:
-        from jax._src import source_info_util
-
-        frame = source_info_util.user_frame(src)
-        if frame is not None:
-            return f"{frame.file_name}:{frame.start_line} ({name})"
-    except Exception:  # provenance is best-effort across jax versions
-        pass
+    frame = (None if src is None
+             else source_info_util.user_frame(src.traceback))
+    if frame is not None:
+        return f"{frame.file_name}:{frame.start_line} ({name})"
     return f"<no source> ({name})"
 
 

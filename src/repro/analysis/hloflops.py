@@ -94,13 +94,14 @@ def dot_flops_for_entry(entry_name: str) -> float:
     from ``repro.analysis.audit.entrypoints.build_targets``), compiled for
     the host platform — lowered and counted, never executed."""
     import jax
+    import jax.extend.core as jex_core
 
     from .audit.entrypoints import build_targets
 
     for ep in build_targets(quick=False):
         if ep.name == entry_name:
             closed = ep.build()
-            fn = jax.core.jaxpr_as_fun(closed)
+            fn = jex_core.jaxpr_as_fun(closed)
             args = [jax.ShapeDtypeStruct(a.shape, a.dtype)
                     for a in closed.in_avals]
             hlo = jax.jit(fn).lower(*args).compile().as_text()

@@ -64,7 +64,7 @@ from .quadratic import (
     from_least_squares_batch,
     lambda_sweep,
     stack_quadratics,
-    weighted_gram,
+    gram,
 )
 from .robust import (
     PreemptedError,
@@ -114,7 +114,7 @@ __all__ = [
     "from_least_squares_batch",
     "lambda_sweep",
     "stack_quadratics",
-    "weighted_gram",
+    "gram",
     "GLM_FAMILIES",
     "GLMObjective",
     "get_objective",

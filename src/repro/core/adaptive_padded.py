@@ -93,11 +93,11 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.precision import canonical_compute_dtype
+from repro.kernels.precision import canonical_compute_dtype, fp32_contractions
 
 from .level_grams import get_provider
 from .precond import shifted_ladder_inverses
-from .quadratic import Quadratic, weighted_gram
+from .quadratic import Quadratic, gram
 from .solvers import c_alpha_rho, rho_to_rate
 from .status import SolveStatus
 
@@ -292,12 +292,12 @@ def _gram_precompute(q: Quadratic, gram_hvp: bool | None, mesh):
     if not gram_hvp:
         return None
     w = q.row_weights
+    if mesh is None:
+        # once, via the chunked compensated Gram: (d, d) for shared A,
+        # (B, d, d) per problem — and AᵀWA per problem even with shared A,
+        # never through an (n, d) weighted copy of A
+        return gram(q.A, w)
     if w is not None:
-        # AᵀWA once, via the chunked streaming Gram (or its sharded psum
-        # variant) — per-problem even with shared A, and never through an
-        # (n, d) weighted copy of A
-        if mesh is None:
-            return weighted_gram(q.A, w)                 # (B, d, d)
         from .distributed import shard_weighted_gram
 
         return shard_weighted_gram(q, mesh)
@@ -542,6 +542,7 @@ def _finalize(pre: PaddedPrecompute, st: PaddedState, *, m_max: int):
 @partial(jax.jit,
          static_argnames=("m_max", "sketch", "gram_hvp", "mesh", "guards",
                           "compute_dtype"))
+@fp32_contractions
 def prepare_padded_solve(
     q: Quadratic,
     keys: jax.Array,
@@ -590,6 +591,7 @@ def prepare_padded_solve(
 
 @partial(jax.jit, static_argnames=("method", "max_iters", "rho", "guards"),
          donate_argnames=("st",))
+@fp32_contractions
 def padded_solve_segment(
     q: Quadratic,
     pre: PaddedPrecompute,
@@ -621,6 +623,7 @@ def padded_solve_segment(
 
 
 @partial(jax.jit, static_argnames=("m_max",))
+@fp32_contractions
 def finalize_padded_solve(pre: PaddedPrecompute, st: PaddedState, *,
                           m_max: int):
     """(x_best, stats) from a terminal — or deadline-paused — state; the
@@ -631,6 +634,7 @@ def finalize_padded_solve(pre: PaddedPrecompute, st: PaddedState, *,
 
 
 @partial(jax.jit, static_argnames=("guards",))
+@fp32_contractions
 def reprecondition_padded(
     q: Quadratic,
     pre: PaddedPrecompute,
@@ -692,6 +696,7 @@ def reprecondition_padded(
 @partial(jax.jit,
          static_argnames=("m_max", "method", "sketch", "max_iters", "rho",
                           "gram_hvp", "mesh", "guards", "compute_dtype"))
+@fp32_contractions
 def padded_adaptive_solve_batched(
     q: Quadratic,
     keys: jax.Array,
@@ -818,6 +823,7 @@ def padded_adaptive_solve_batched(
 @partial(jax.jit,
          static_argnames=("m_max", "sketch", "gram_hvp", "mesh",
                           "compute_dtype"))
+@fp32_contractions
 def prepare_path_ladder(
     q: Quadratic,
     keys: jax.Array,

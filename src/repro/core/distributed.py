@@ -47,30 +47,29 @@ further collectives.
 
 from __future__ import annotations
 
-
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from .level_grams import LevelGramProvider
 from .precond import factorize
 from .quadratic import Quadratic
 from .sketches import make_sketch
 
-# jax ≥ 0.6 exposes jax.shard_map(check_vma=...); 0.4.x/0.5.x only the
-# experimental entry point with the older check_rep spelling.
-if hasattr(jax, "shard_map"):
-    _shard_map_fn, _CHECK_KW = jax.shard_map, "check_vma"
-else:  # pragma: no cover - depends on installed jax
-    from jax.experimental.shard_map import shard_map as _shard_map_fn
 
-    _CHECK_KW = "check_rep"
+def gspmd_mesh(mesh: Mesh) -> Mesh:
+    """``mesh`` with every axis ``Auto``: the sharded engine is a GSPMD
+    program (XLA places the in-loop reductions), while ``jax.make_mesh``
+    builds ``Explicit`` axes, under which a contraction over the sharded
+    row axis is refused as ambiguous."""
+    return Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
 
 
 def _smap(f, mesh: Mesh, in_specs, out_specs):
-    """shard_map with replication checking off, on every supported jax."""
-    return _shard_map_fn(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, **{_CHECK_KW: False})
+    """shard_map with replication checking off."""
+    return jax.shard_map(f, mesh=gspmd_mesh(mesh), in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def data_axes(mesh: Mesh) -> tuple[str, ...]:
@@ -108,6 +107,7 @@ def shard_quadratic(q: Quadratic, mesh: Mesh) -> Quadratic:
 
     Works for single problems, per-problem batches (B, n, d) and shared-A
     batches alike; the ``batched`` flag is preserved."""
+    mesh = gspmd_mesh(mesh)
     a_sh = NamedSharding(mesh, _a_row_spec(q, mesh))
     rep = NamedSharding(mesh, P())
     w = q.row_weights
@@ -358,10 +358,10 @@ class ShardLadderCache:
 
 def shard_weighted_gram(q: Quadratic, mesh: Mesh) -> jnp.ndarray:
     """(B, d, d) AᵀWA for a row-sharded weighted batch: each shard runs the
-    chunked streaming Gram (``quadratic.weighted_gram``) on its local row
+    chunked streaming Gram (``quadratic.gram``) on its local row
     block — no (n, d) weighted copy of A anywhere — and ONE psum combines
     the block Grams (AᵀWA = Σ_k A_kᵀW_kA_k exactly: W is row-diagonal)."""
-    from .quadratic import weighted_gram
+    from .quadratic import gram
 
     if not q.batched or q.row_weights is None:
         raise ValueError("shard_weighted_gram expects a batched, weighted "
@@ -370,7 +370,7 @@ def shard_weighted_gram(q: Quadratic, mesh: Mesh) -> jnp.ndarray:
     _check_divisible(q.n, mesh)
 
     def local_gram(A_blk, w_blk):
-        return jax.lax.psum(weighted_gram(A_blk, w_blk), axis_name=da)
+        return jax.lax.psum(gram(A_blk, w_blk), axis_name=da)
 
     fn = _smap(local_gram, mesh,
                in_specs=(_a_row_spec(q, mesh), _w_row_spec(q, mesh)),
@@ -446,6 +446,7 @@ def quadratic_shardings(mesh: Mesh, q: Quadratic | None = None) -> Quadratic:
 
     Pass ``q`` to pick the batched layouts (per-problem A shards axis 1);
     without it the single-problem (n, d) layout is assumed."""
+    mesh = gspmd_mesh(mesh)
     da = data_axes(mesh)
     a_spec = _a_row_spec(q, mesh) if q is not None else P(da, None)
     batched = bool(q.batched) if q is not None else False

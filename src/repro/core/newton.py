@@ -40,6 +40,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.precision import fp32_contractions
+
 from .adaptive_padded import _is_single_key, padded_adaptive_solve_batched
 from .objectives import (
     GLMObjective,
@@ -52,11 +54,13 @@ from .status import SolveStatus
 
 
 @partial(jax.jit, static_argnames=("obj",))
+@fp32_contractions
 def _grad_and_weights(obj: GLMObjective, A, y, nu, lam, x):
     return glm_grad_and_weights(obj, A, y, nu, lam, x)
 
 
 @partial(jax.jit, static_argnames=("obj", "backtracks", "c1"))
+@fp32_contractions
 def _line_search(obj: GLMObjective, A, y, nu, lam, x, delta, dec, active,
                  *, backtracks: int, c1: float):
     """Per-problem backtracking Armijo: largest s ∈ {1, ½, …, 2^{1−K}} with

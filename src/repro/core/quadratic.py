@@ -36,6 +36,8 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.precision import fp32_contractions
+
 
 def pdot(a: jnp.ndarray, b: jnp.ndarray, batched: bool) -> jnp.ndarray:
     """⟨a, b⟩ summed over all axes — except the leading problem axis when
@@ -168,6 +170,7 @@ def _as_batched_reg(nu, lam_diag, B: int, d: int, dtype):
     return nu, lam_diag
 
 
+@fp32_contractions
 def from_least_squares(A, y, nu, lam_diag=None) -> Quadratic:
     """Ridge regression  min ½‖Ax − y‖² + ν²/2 ‖Λ^{1/2}x‖²  as (1.1)."""
     A = jnp.asarray(A)
@@ -177,6 +180,7 @@ def from_least_squares(A, y, nu, lam_diag=None) -> Quadratic:
     return Quadratic(A=A, b=A.T @ y, nu=jnp.asarray(nu, A.dtype), lam_diag=lam_diag)
 
 
+@fp32_contractions
 def from_least_squares_batch(A, Y, nu, lam_diag=None) -> Quadratic:
     """Batched ridge:  A (B, n, d) per-problem or (n, d) shared; Y (B, n);
     ν scalar or (B,); Λ (d,) or (B, d)."""
@@ -191,6 +195,7 @@ def from_least_squares_batch(A, Y, nu, lam_diag=None) -> Quadratic:
     return Quadratic(A=A, b=b, nu=nu, lam_diag=lam_diag, batched=True)
 
 
+@fp32_contractions
 def lambda_sweep(A, y, nus, lam_diag=None) -> Quadratic:
     """Shared-A regularization-path batch: one (A, y), B values of ν.
 
@@ -226,41 +231,58 @@ def stack_quadratics(qs: list[Quadratic]) -> Quadratic:
                      row_weights=w)
 
 
-def weighted_gram(A: jnp.ndarray, w: jnp.ndarray, *,
-                  chunk: int = 1024) -> jnp.ndarray:
-    """AᵀWA as (B, d, d) without materializing W^{1/2}A: a ``lax.scan``
-    over n-chunks whose only weighted intermediate is the (B, chunk, d)
-    tile — never an (n, d)-sized weighted copy of A (the streaming
-    guarantee the engine's weighted ``gram_hvp`` relies on).
+def gram(A: jnp.ndarray, w: jnp.ndarray | None = None, *,
+         chunk: int = 1024) -> jnp.ndarray:
+    """AᵀA — or AᵀWA with per-problem row weights w (B, n) — as a
+    ``lax.scan`` over n-chunks with Kahan-compensated accumulation.
 
-    A is (B, n, d) per-problem or (n, d) shared; w is (B, n)."""
+    A is (B, n, d) per-problem or (n, d) shared; the result is (B, d, d),
+    or (d, d) for a shared, unweighted A. The only weighted intermediate is
+    the (B, chunk, d) tile — never an (n, d)-sized weighted copy of A (the
+    streaming guarantee the engine's weighted ``gram_hvp`` relies on).
+
+    Why chunks and compensation: the certificates solve against this Gram,
+    and one fp32 dot over all n rows accumulates its rounding error along
+    n. On a TPU v5e at n = 2¹⁷ that error is 1.3e-5 relative even at
+    HIGHEST precision, which a condition number of 10³ turns into 1e-3 in
+    x. Per-chunk Grams summed with Kahan compensation keep the error near
+    fp32 rounding of the result, independent of n."""
     shared = A.ndim == 2
     n, d = A.shape[-2], A.shape[-1]
-    B = w.shape[0]
     chunk = min(chunk, n)
     pad = (-n) % chunk
     if pad:
-        # zero rows carry zero weight: they add exact zeros to the Gram
+        # zero rows add exact zeros to the Gram
         A = jnp.pad(A, ((0, pad), (0, 0)) if shared
                     else ((0, 0), (0, pad), (0, 0)))
-        w = jnp.pad(w, ((0, 0), (0, pad)))
-    steps = (n + pad) // chunk
+        if w is not None:
+            w = jnp.pad(w, ((0, 0), (0, pad)))
+    hi = jax.lax.Precision.HIGHEST
 
-    def step(acc, c_idx):
+    def step(carry, c_idx):
+        total, comp = carry
         r0 = c_idx * chunk
         a_c = jax.lax.dynamic_slice_in_dim(A, r0, chunk, axis=A.ndim - 2)
-        w_c = jax.lax.dynamic_slice_in_dim(w, r0, chunk, axis=1)
-        if shared:
-            g = jnp.einsum("bc,cd,ce->bde", w_c, a_c, a_c)
+        if w is None:
+            g = (jnp.matmul(a_c.T, a_c, precision=hi) if shared
+                 else jnp.einsum("bcd,bce->bde", a_c, a_c, precision=hi))
         else:
-            g = jnp.einsum("bc,bcd,bce->bde", w_c, a_c, a_c)
-        return acc + g, None
+            w_c = jax.lax.dynamic_slice_in_dim(w, r0, chunk, axis=1)
+            g = jnp.einsum("bc,cd,ce->bde" if shared else "bc,bcd,bce->bde",
+                           w_c, a_c, a_c, precision=hi)
+        g = g - comp
+        new = total + g
+        return (new, (new - total) - g), None
 
-    acc0 = jnp.zeros((B, d, d), A.dtype)
-    acc, _ = jax.lax.scan(step, acc0, jnp.arange(steps))
-    return acc
+    shape = ((d, d) if shared and w is None
+             else ((A.shape[0] if w is None else w.shape[0]), d, d))
+    zero = jnp.zeros(shape, A.dtype)
+    (total, _), _ = jax.lax.scan(step, (zero, zero),
+                                 jnp.arange((n + pad) // chunk))
+    return total
 
 
+@fp32_contractions
 def direct_solve(q: Quadratic) -> jnp.ndarray:
     """Baseline: dense Cholesky factor-and-solve, O(nd²+d³) (paper baseline).
 

@@ -11,27 +11,12 @@ import statistics
 import time
 from typing import Callable, Optional
 
-
-# jax ≥ 0.5 exposes AxisType and takes AbstractMesh(axis_sizes, axis_names);
-# 0.4.x has neither the enum nor that signature (AbstractMesh takes a
-# ((name, size), ...) shape tuple). Normalize behind one constructor so
-# planning code is version-independent.
-from jax.sharding import AbstractMesh
-
-try:  # jax ≥ 0.5
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - depends on installed jax
-    AxisType = None
+from jax.sharding import AbstractMesh, AxisType
 
 
 def _abstract_mesh(shape: tuple[int, ...], names: tuple[str, ...]):
-    if AxisType is not None:
-        try:
-            return AbstractMesh(
-                shape, names, axis_types=tuple(AxisType.Auto for _ in names))
-        except TypeError:  # pre-0.6 keyword variants
-            return AbstractMesh(shape, names)
-    return AbstractMesh(tuple(zip(names, shape)))
+    return AbstractMesh(shape, names,
+                        axis_types=tuple(AxisType.Auto for _ in names))
 
 
 # ---------------------------------------------------------------------------

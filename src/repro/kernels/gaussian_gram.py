@@ -35,8 +35,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from .precision import canonical_compute_dtype, contract_dtype
+from .precision import canonical_compute_dtype, contract_dtype, fp32_precision
 
 # Canonical micro-tile of the n axis: the oracle always reduces n in
 # _MICRO-column steps so chunk size never changes numerics; the Pallas
@@ -75,9 +76,12 @@ def gaussian_tile(seed, row0, col0, shape) -> jnp.ndarray:
     h1 = _mix(ctr ^ k)
     h2 = _mix(h1 + _SEQ2)
     # 24-bit mantissas; u1 offset into (0, 1) so log(u1) is finite
-    u1 = (h1 >> 8).astype(jnp.float32) * (1.0 / 16777216.0) + (
-        0.5 / 16777216.0)
-    u2 = (h2 >> 8).astype(jnp.float32) * (1.0 / 16777216.0)
+    # the 24-bit values convert through int32 (exact): Mosaic has no
+    # uint32 → float32 conversion
+    u1 = (h1 >> 8).astype(jnp.int32).astype(jnp.float32) * (
+        1.0 / 16777216.0) + (0.5 / 16777216.0)
+    u2 = (h2 >> 8).astype(jnp.int32).astype(jnp.float32) * (
+        1.0 / 16777216.0)
     return jnp.sqrt(-2.0 * jnp.log(u1)) * jnp.cos(6.2831853071795864 * u2)
 
 
@@ -207,18 +211,32 @@ def gaussian_sa_ref(A: jnp.ndarray, seeds: jnp.ndarray, m: int, *,
 # Pallas kernel — grid (B, n/chunk), S tile generated in VMEM per cell
 # ---------------------------------------------------------------------------
 
-def _gauss_sa_kernel(seed_ref, a_ref, o_ref, *, m: int, chunk: int, ct):
+def _gauss_sa_kernel(*refs, m: int, chunk: int, ct, scaled: bool):
+    """One (problem, chunk) cell. ``seed_ref`` is the whole (B,) seed table
+    in SMEM (a (1,) VMEM block per problem breaks the rank-1 tiling rule).
+    With ``scaled`` a (1, 1, chunk) block of the pre-folded fp32
+    per-column factor — w^{1/2} (GLM weights), int8 dequantization scales,
+    or their product (``resolve_stream``) — scales the generated S tile's
+    columns in VMEM before the contraction: S·diag(s)·A fused, with
+    neither S nor the scaled A ever in HBM; on the int8 path ``a`` holds
+    codes that are dequantized in-register by this scale."""
+    seed_ref, s_ref, a_ref, o_ref = refs if scaled else (refs[0], None,
+                                                         *refs[1:])
     c = pl.program_id(1)
-    seed = seed_ref[0]
+    seed = seed_ref[pl.program_id(0)]
     col0 = (c * chunk).astype(jnp.uint32)
     S = gaussian_tile(seed, 0, col0, (m, chunk))   # VMEM-only, never in HBM
+    if scaled:
+        S = S * s_ref[0].astype(jnp.float32)
     a = a_ref[...]
     if a.ndim == 3:
         a = a[0]
     # ct is the contract dtype (kernels.precision): fp32 or bf16. The cast
     # happens on the VMEM tile/chunk in-register; the MXU accumulates fp32
-    # via preferred_element_type either way.
+    # via preferred_element_type either way, and an fp32 contraction asks
+    # for full fp32 passes.
     acc = jnp.dot(S.astype(ct), a.astype(ct),
+                  precision=fp32_precision(ct),
                   preferred_element_type=jnp.float32)
 
     @pl.when(c == 0)
@@ -231,33 +249,12 @@ def _gauss_sa_kernel(seed_ref, a_ref, o_ref, *, m: int, chunk: int, ct):
             o_ref.dtype)
 
 
-def _gauss_sa_kernel_scaled(seed_ref, s_ref, a_ref, o_ref, *, m: int,
-                            chunk: int, ct):
-    """Scaled variant: the generated (m, chunk) S tile's columns are scaled
-    by a pre-folded fp32 per-column factor in VMEM before the MXU
-    contraction — w^{1/2} (GLM weights), int8 dequantization scales, or
-    their product (``resolve_stream``) all ride the same slot. S·diag(s)·A
-    fused, with neither S nor the scaled A ever in HBM; on the int8 path
-    ``a`` holds codes that are dequantized in-register by this scale."""
-    c = pl.program_id(1)
-    seed = seed_ref[0]
-    col0 = (c * chunk).astype(jnp.uint32)
-    S = gaussian_tile(seed, 0, col0, (m, chunk))
-    S = S * s_ref[0, :].astype(jnp.float32)[None, :]
-    a = a_ref[...]
-    if a.ndim == 3:
-        a = a[0]
-    acc = jnp.dot(S.astype(ct), a.astype(ct),
-                  preferred_element_type=jnp.float32)
-
-    @pl.when(c == 0)
-    def _init():
-        o_ref[0, ...] = acc.astype(o_ref.dtype)
-
-    @pl.when(c > 0)
-    def _acc():
-        o_ref[0, ...] = (o_ref[0, ...].astype(jnp.float32) + acc).astype(
-            o_ref.dtype)
+def vmem_bytes(m: int, chunk: int, d: int, itemsize: int) -> int:
+    """Scoped VMEM the kernel asks for: double-buffered A chunk and (m, d)
+    fp32 accumulator blocks, plus ~10 (m, chunk) 32-bit tiles for the
+    counter hash and Box–Muller temporaries."""
+    return (2 * chunk * d * itemsize + 2 * m * d * 4
+            + 10 * m * chunk * 4 + (2 << 20))
 
 
 def gaussian_sa_pallas(
@@ -276,14 +273,16 @@ def gaussian_sa_pallas(
     Grid (B, n/chunk): each cell generates its (m, chunk) S tile from the
     counter hash in VMEM and contracts it with the A chunk on the MXU;
     the output block is revisited over the chunk axis (accumulator
-    pattern). VMEM per step: m·chunk (S) + chunk·d (A) + m·d (acc); with
-    m ≤ 1024, chunk = 512, d ≤ 512 this stays ≤ ~4 MiB. Entries match
-    ``gaussian_sa_ref`` / ``gaussian_s_dense`` bit-for-bit (same counter
-    hash); the contraction differs only in reduction order.
+    pattern). VMEM per step (``vmem_bytes``): two A chunks, two (m, d)
+    accumulators and the hash temporaries — 34 MiB asked at m = d = 1024,
+    chunk = 512 (fp32), where the accumulator block alone is 4 MiB and the
+    v5e compiler needs 20 MiB (7 MiB at m = 512, d = 256).
+    Entries match ``gaussian_sa_ref`` / ``gaussian_s_dense`` bit-for-bit
+    (same counter hash); the contraction differs only in reduction order.
 
     ``row_weights`` (B, n) switches to the scaled kernel: the S tile is
-    scaled by w^{1/2} in VMEM (one extra (1, chunk) block input per cell);
-    W^{1/2}A never exists in HBM.
+    scaled by w^{1/2} in VMEM (one extra (1, 1, chunk) block of a
+    (B, 1, n) view per cell); W^{1/2}A never exists in HBM.
 
     ``compute_dtype`` (``kernels.precision``): ``"bf16"`` casts the S tile
     and A chunk to bfloat16 in-register for the MXU's bf16×bf16→fp32 mode
@@ -305,33 +304,27 @@ def gaussian_sa_pallas(
         if scale is not None:
             scale = jnp.pad(scale, ((0, 0), (0, pad)))
         n = n + pad
-    grid = (B, n // chunk)
+    scaled = scale is not None
     a_spec = (
         pl.BlockSpec((chunk, d), lambda b, c: (c, 0))
         if shared
         else pl.BlockSpec((1, chunk, d), lambda b, c: (b, c, 0))
     )
-    if scale is None:
-        return pl.pallas_call(
-            functools.partial(_gauss_sa_kernel, m=m, chunk=chunk, ct=ct),
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((1,), lambda b, c: (b,)),
-                a_spec,
-            ],
-            out_specs=pl.BlockSpec((1, m, d), lambda b, c: (b, 0, 0)),
-            out_shape=jax.ShapeDtypeStruct((B, m, d), out_dtype),
-            interpret=interpret,
-        )(seeds.astype(jnp.uint32), A)
+    in_specs = [pl.BlockSpec(memory_space=pltpu.SMEM), a_spec]
+    args = [seeds.astype(jnp.uint32), A]
+    if scaled:
+        in_specs.insert(1, pl.BlockSpec((1, 1, chunk),
+                                        lambda b, c: (b, 0, c)))
+        args.insert(1, scale.astype(jnp.float32).reshape(B, 1, n))
     return pl.pallas_call(
-        functools.partial(_gauss_sa_kernel_scaled, m=m, chunk=chunk, ct=ct),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1,), lambda b, c: (b,)),
-            pl.BlockSpec((1, chunk), lambda b, c: (b, c)),
-            a_spec,
-        ],
+        functools.partial(_gauss_sa_kernel, m=m, chunk=chunk, ct=ct,
+                          scaled=scaled),
+        grid=(B, n // chunk),
+        in_specs=in_specs,
         out_specs=pl.BlockSpec((1, m, d), lambda b, c: (b, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, m, d), out_dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=vmem_bytes(m, chunk, d, A.dtype.itemsize)),
         interpret=interpret,
-    )(seeds.astype(jnp.uint32), scale.astype(jnp.float32), A)
+    )(*args)

@@ -1,8 +1,12 @@
-"""jit'd public wrappers for the Pallas kernels, with CPU fallbacks.
+"""jit'd public wrappers for the Pallas kernels.
 
-On TPU the kernels run compiled; on CPU (this container) they run in
-``interpret=True`` mode, which executes the kernel body step-by-step for
-correctness validation. ``use_pallas=None`` auto-selects by backend.
+On a TPU the kernels always run compiled: ``use_pallas=False`` or
+``interpret=True`` there is an error, never a quiet fallback to a
+reference. On other backends ``use_pallas=None`` selects the jnp
+references, and ``use_pallas=True`` runs the kernel bodies in the Pallas
+interpreter (``interpret`` defaults to True off-TPU) — how the tests check
+kernel semantics on a CPU. ``use_pallas=True, interpret=False`` compiles
+for a TPU target even from a CPU process (the compile rehearsal).
 """
 
 from __future__ import annotations
@@ -20,11 +24,25 @@ from .precision import canonical_compute_dtype, contract_dtype
 from .sjlt import fold_row_weights as sjlt_fold_row_weights
 from .sjlt import sjlt_pallas, sjlt_pallas_batched
 
-_FWHT_VMEM_MAX_N = 16_384  # n · 128 cols · 4 B ≈ 8 MiB
+# largest n one in-VMEM FWHT pass takes: an (n, 128) fp32 block is 8 MiB,
+# and the pass needs 33 MiB of scoped VMEM on v5e (fwht.vmem_bytes)
+_FWHT_VMEM_MAX_N = 16_384
 
 
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
+
+
+def _kernel_mode(use_pallas: bool | None,
+                 interpret: bool | None) -> tuple[bool, bool]:
+    """Resolve (use_pallas, interpret) for one call (see module doc)."""
+    if _on_tpu():
+        if use_pallas is False or interpret:
+            raise ValueError(
+                "on a TPU the Pallas kernels run compiled; use_pallas=False "
+                "and interpret=True are for other backends")
+        return True, False
+    return bool(use_pallas), (True if interpret is None else interpret)
 
 
 @functools.partial(jax.jit,
@@ -43,10 +61,7 @@ def fwht(x: jnp.ndarray, *, use_pallas: bool | None = None,
     in-register, halving the transform's VMEM/HBM footprint; an int8 ``x``
     (quantized codes ≤ 127, exact in bf16) rides the same cast. The final
     Gram contraction downstream stays fp32 (the SRHT provider's einsum)."""
-    if use_pallas is None:
-        use_pallas = _on_tpu()
-    if interpret is None:
-        interpret = not _on_tpu()
+    use_pallas, interpret = _kernel_mode(use_pallas, interpret)
     if canonical_compute_dtype(compute_dtype) != "fp32":
         ct = contract_dtype(compute_dtype)
         x = x.astype(ct)
@@ -95,10 +110,7 @@ def sjlt_apply(A: jnp.ndarray, rows: jnp.ndarray, signs: jnp.ndarray, m: int,
     (n,) computes S·W^{1/2}·A by folding w^{1/2} into the signs;
     ``compute_dtype`` selects the bf16 dispatch-matmul / int8-codes stream
     (``kernels.precision``) on both backends."""
-    if use_pallas is None:
-        use_pallas = _on_tpu()
-    if interpret is None:
-        interpret = not _on_tpu()
+    use_pallas, interpret = _kernel_mode(use_pallas, interpret)
     signs = sjlt_fold_row_weights(signs, row_weights)
     if not use_pallas:
         return ref.sjlt_ref(A, rows, signs, m, compute_dtype=compute_dtype)
@@ -118,10 +130,7 @@ def sjlt_apply_batched(A: jnp.ndarray, rows: jnp.ndarray, signs: jnp.ndarray,
     ``row_weights`` (B, n) folds per-problem w^{1/2} into the sign stream
     — the weighted matrix W^{1/2}A never exists; ``compute_dtype`` rides
     the same slot (``kernels.precision``)."""
-    if use_pallas is None:
-        use_pallas = _on_tpu()
-    if interpret is None:
-        interpret = not _on_tpu()
+    use_pallas, interpret = _kernel_mode(use_pallas, interpret)
     signs = sjlt_fold_row_weights(signs, row_weights)
     if not use_pallas:
         return ref.sjlt_ref_batched(A, rows, signs, m,
@@ -149,10 +158,7 @@ def gaussian_sa(A: jnp.ndarray, seeds: jnp.ndarray, m: int, *,
     W^{1/2}A is ever materialized. ``compute_dtype`` selects the bf16 tile
     stream / int8-codes path (``kernels.precision``); both backends share
     the same dtype simulation, so results match per mode."""
-    if use_pallas is None:
-        use_pallas = _on_tpu()
-    if interpret is None:
-        interpret = not _on_tpu()
+    use_pallas, interpret = _kernel_mode(use_pallas, interpret)
     if not use_pallas:
         return gaussian_sa_ref(A, seeds, m,
                                chunk_cols=chunk_cols or 2048,

@@ -35,6 +35,9 @@ path.
 
 from __future__ import annotations
 
+import functools
+
+import jax
 import jax.numpy as jnp
 
 COMPUTE_DTYPES = ("fp32", "bf16", "int8")
@@ -55,6 +58,33 @@ def contract_dtype(compute_dtype: str | None):
     (accumulation is always fp32 via ``preferred_element_type``)."""
     return (jnp.float32 if canonical_compute_dtype(compute_dtype) == "fp32"
             else jnp.bfloat16)
+
+
+def fp32_precision(ct):
+    """The ``precision=`` of a contraction in dtype ``ct``: full fp32
+    passes for fp32 operands (a TPU's default runs an fp32 dot as one bf16
+    pass), the MXU's native mode for bf16 — stated explicitly, so that an
+    enclosing ``fp32_contractions`` scope does not reach it."""
+    return (jax.lax.Precision.HIGHEST if ct == jnp.float32
+            else jax.lax.Precision.DEFAULT)
+
+
+def fp32_contractions(fn):
+    """Trace (or run) ``fn`` with fp32 as the default matmul precision.
+
+    The engine's entry points wear this under their ``jax.jit``: every
+    contraction the certificates depend on — the hvp, the exact and ladder
+    Grams, the factorizations' matmuls, residuals and δ̃ — then runs at
+    fp32 on a TPU, where an fp32 dot would otherwise be one bf16 pass
+    (~4e-3 relative, far above the service's δ̃ tolerance). Sketch-stream
+    contractions that state their own precision (``fp32_precision``) keep
+    it. CPU backends compute fp32 dots in fp32 either way, so results there
+    are unchanged bit for bit."""
+    @functools.wraps(fn)
+    def scoped(*args, **kwargs):
+        with jax.default_matmul_precision("float32"):
+            return fn(*args, **kwargs)
+    return scoped
 
 
 def stream_itemsize(compute_dtype: str | None) -> int:

@@ -14,14 +14,15 @@ The grid walks row blocks sequentially; the output block is revisited
 accumulator pattern. Dense systolic work replaces data-dependent scatter:
 bandwidth-bound instead of latency-bound.
 
-Batched variant (DESIGN.md §6): ``sjlt_pallas_batched`` adds a leading
-problem axis to the grid — grid (B, n/br), one dispatch-matmul cell per
+Batched layout (DESIGN.md §6): ``sjlt_pallas_batched`` puts a leading
+problem axis on the grid — grid (B, n/br), one dispatch-matmul cell per
 (problem, row-block). The problem axis is the outer (slowest) grid
 dimension, so each problem's output block sees its row-blocks sequentially
 and the same revisited-accumulator pattern applies per problem. The data
 matrix may be per-problem (B, n, d) or shared (n, d) across the batch
 (λ-sweep / multi-tenant serving); in the shared case the A tile is fetched
-once per row-block index by the pipeline, not once per problem.
+once per row-block index by the pipeline, not once per problem. The
+single-problem ``sjlt_pallas`` is this kernel with B = 1.
 """
 
 from __future__ import annotations
@@ -31,8 +32,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from .precision import canonical_compute_dtype, contract_dtype
+from .precision import canonical_compute_dtype, contract_dtype, fp32_precision
 
 
 def fold_row_weights(signs: jnp.ndarray,
@@ -69,30 +71,6 @@ def fold_stream(A: jnp.ndarray, signs: jnp.ndarray,
     return A, signs, ct, out_dtype
 
 
-def _sjlt_kernel(rows_ref, signs_ref, a_ref, o_ref, *, m: int, ct):
-    i = pl.program_id(0)
-    rows = rows_ref[...]            # (br,) int32 target row per A-row
-    signs = signs_ref[...]          # (br,) ±1/√s (× w^{1/2} / int8 scales)
-    a = a_ref[...]                  # (br, bd)
-    br = a.shape[0]
-    # signed one-hot dispatch (m, br) built in VMEM; ct is the contract
-    # dtype (fp32/bf16) — bf16 folds the sign stream into the MXU's native
-    # mixed mode, fp32 accumulation via preferred_element_type either way
-    row_ids = jax.lax.broadcasted_iota(jnp.int32, (m, br), 0)
-    onehot = jnp.where(row_ids == rows[None, :], signs[None, :], 0.0).astype(
-        ct
-    )
-    acc = jnp.dot(onehot, a.astype(ct), preferred_element_type=jnp.float32)
-
-    @pl.when(i == 0)
-    def _init():
-        o_ref[...] = acc.astype(o_ref.dtype)
-
-    @pl.when(i > 0)
-    def _acc():
-        o_ref[...] = (o_ref[...].astype(jnp.float32) + acc).astype(o_ref.dtype)
-
-
 def sjlt_pallas(
     A: jnp.ndarray,
     rows: jnp.ndarray,
@@ -104,52 +82,33 @@ def sjlt_pallas(
     row_weights: jnp.ndarray | None = None,
     compute_dtype: str | None = None,
 ) -> jnp.ndarray:
-    """S @ A for an s=1 SJLT. A: (n, d); rows/signs: (n,). Returns (m, d).
-    ``row_weights`` (n,) computes S·W^{1/2}·A by folding w^{1/2} into the
-    sign stream (``fold_row_weights``); ``compute_dtype`` runs the
-    dispatch-matmul in bf16 / streams int8 codes (``fold_stream``).
-
-    VMEM per step: br·d (A tile) + m·br (one-hot) + m·d (accumulator);
-    with br=256, m≤2048, d-tile = full d this targets ≤ ~8 MiB for d ≤ 4k.
-    """
-    signs = fold_row_weights(signs, row_weights)
-    A, signs, ct, out_dtype = fold_stream(A, signs, compute_dtype)
-    n, d = A.shape
-    if n % block_rows:
-        pad = (-n) % block_rows
-        A = jnp.pad(A, ((0, pad), (0, 0)))
-        rows = jnp.pad(rows, (0, pad), constant_values=m)  # m = out of range
-        signs = jnp.pad(signs, (0, pad))
-        n = A.shape[0]
-    grid = (n // block_rows,)
-    out = pl.pallas_call(
-        functools.partial(_sjlt_kernel, m=m, ct=ct),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_rows,), lambda i: (i,)),
-            pl.BlockSpec((block_rows,), lambda i: (i,)),
-            pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((m, d), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((m, d), out_dtype),
-        interpret=interpret,
-    )(rows.astype(jnp.int32), signs.astype(jnp.float32), A)
-    return out
+    """S @ A for an s=1 SJLT. A: (n, d); rows/signs: (n,). Returns (m, d):
+    the batched kernel with one problem. ``row_weights`` (n,) computes
+    S·W^{1/2}·A by folding w^{1/2} into the sign stream
+    (``fold_row_weights``); ``compute_dtype`` runs the dispatch-matmul in
+    bf16 / streams int8 codes (``fold_stream``)."""
+    weights = None if row_weights is None else row_weights[None]
+    return sjlt_pallas_batched(A, rows[None], signs[None], m,
+                               block_rows=block_rows, interpret=interpret,
+                               row_weights=weights,
+                               compute_dtype=compute_dtype)[0]
 
 
-def _sjlt_kernel_batched(rows_ref, signs_ref, a_ref, o_ref, *, m: int, ct):
+def _sjlt_kernel(rows_ref, signs_ref, a_ref, o_ref, *, m: int, ct):
     j = pl.program_id(1)            # row-block index (inner grid dim)
-    rows = rows_ref[0, :]           # (br,) this problem's targets
-    signs = signs_ref[0, :]
+    rows = rows_ref[0]              # (1, br) this problem's targets
+    signs = signs_ref[0]            # (1, br) ±1/√s (× w^{1/2} / int8 scales)
     a = a_ref[...]                  # (br, d) or (1, br, d) per-problem
     if a.ndim == 3:
         a = a[0]
     br = a.shape[0]
+    # signed one-hot dispatch (m, br) built in VMEM; ct is the contract
+    # dtype (fp32/bf16) — bf16 folds the sign stream into the MXU's native
+    # mixed mode, fp32 accumulation via preferred_element_type either way
     row_ids = jax.lax.broadcasted_iota(jnp.int32, (m, br), 0)
-    onehot = jnp.where(row_ids == rows[None, :], signs[None, :], 0.0).astype(
-        ct
-    )
-    acc = jnp.dot(onehot, a.astype(ct), preferred_element_type=jnp.float32)
+    onehot = jnp.where(row_ids == rows, signs, 0.0).astype(ct)
+    acc = jnp.dot(onehot, a.astype(ct), precision=fp32_precision(ct),
+                  preferred_element_type=jnp.float32)
 
     @pl.when(j == 0)
     def _init():
@@ -160,6 +119,14 @@ def _sjlt_kernel_batched(rows_ref, signs_ref, a_ref, o_ref, *, m: int, ct):
         o_ref[0, ...] = (o_ref[0, ...].astype(jnp.float32) + acc).astype(
             o_ref.dtype
         )
+
+
+def vmem_bytes(m: int, br: int, d: int, itemsize: int) -> int:
+    """Scoped VMEM the kernel asks for: double-buffered A tile, (m, d) fp32
+    accumulator and (1, br) row/sign blocks (padded to 8 sublanes), plus
+    the (m, br) one-hot and its compare mask."""
+    return (2 * br * d * itemsize + 2 * m * d * 4 + 4 * 8 * br * 4
+            + 3 * m * br * 4 + (2 << 20))
 
 
 def sjlt_pallas_batched(
@@ -184,9 +151,12 @@ def sjlt_pallas_batched(
     (``fold_stream``) — the shared-A fast path survives quantization for
     the same reason it survives weights.
 
-    The problem axis is the outer grid dimension so the per-problem output
-    block accumulates over its row-blocks exactly as in ``sjlt_pallas``;
-    VMEM per step is unchanged from the single-problem kernel.
+    The problem axis is the outer grid dimension so each problem's output
+    block accumulates over its row-blocks. Rows and signs ride as (B, 1, n)
+    views with (1, 1, block_rows) blocks, which the TPU tiling accepts.
+    VMEM per step (``vmem_bytes``): br·d (A tile) + m·br (one-hot) + m·d
+    (accumulator), double-buffered — the v5e compiler needs 4 MiB at
+    m = 512, d = 256.
     """
     signs = fold_row_weights(signs, row_weights)
     A, signs, ct, out_dtype = fold_stream(A, signs, compute_dtype)
@@ -202,22 +172,23 @@ def sjlt_pallas_batched(
         rows = jnp.pad(rows, ((0, 0), (0, pad)), constant_values=m)
         signs = jnp.pad(signs, ((0, 0), (0, pad)))
         n = A.shape[-2]
-    grid = (B, n // block_rows)
     a_spec = (
         pl.BlockSpec((block_rows, d), lambda b, j: (j, 0))
         if shared
         else pl.BlockSpec((1, block_rows, d), lambda b, j: (b, j, 0))
     )
+    stream_spec = pl.BlockSpec((1, 1, block_rows), lambda b, j: (b, 0, j))
     out = pl.pallas_call(
-        functools.partial(_sjlt_kernel_batched, m=m, ct=ct),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_rows), lambda b, j: (b, j)),
-            pl.BlockSpec((1, block_rows), lambda b, j: (b, j)),
-            a_spec,
-        ],
+        functools.partial(_sjlt_kernel, m=m, ct=ct),
+        grid=(B, n // block_rows),
+        in_specs=[stream_spec, stream_spec, a_spec],
         out_specs=pl.BlockSpec((1, m, d), lambda b, j: (b, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, m, d), out_dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=vmem_bytes(m, block_rows, d,
+                                        A.dtype.itemsize)),
         interpret=interpret,
-    )(rows.astype(jnp.int32), signs.astype(jnp.float32), A)
+    )(rows.astype(jnp.int32).reshape(B, 1, n),
+      signs.astype(jnp.float32).reshape(B, 1, n), A)
     return out

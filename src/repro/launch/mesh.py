@@ -9,22 +9,12 @@ runtime.
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
-
-try:  # jax >= 0.5
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - depends on installed jax
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 
 def _make_mesh(shape, axes) -> Mesh:
-    if AxisType is not None:
-        try:
-            return jax.make_mesh(shape, axes,
-                                 axis_types=(AxisType.Auto,) * len(axes))
-        except TypeError:
-            pass
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -60,10 +50,5 @@ def make_elastic_mesh(n_devices: int) -> Mesh:
     import numpy as np
 
     dev_array = np.array(devices).reshape(data, model)
-    if AxisType is not None:
-        try:
-            return Mesh(dev_array, ("data", "model"),
-                        axis_types=(AxisType.Auto, AxisType.Auto))
-        except TypeError:
-            pass
-    return Mesh(dev_array, ("data", "model"))
+    return Mesh(dev_array, ("data", "model"),
+                axis_types=(AxisType.Auto, AxisType.Auto))

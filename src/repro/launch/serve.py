@@ -45,6 +45,7 @@ import jax.numpy as jnp
 
 from repro.configs import get_config
 from repro.ft import CheckpointManager
+from repro.launch.runtime import enable_compile_cache
 from repro.models import init_params
 from repro.serve.step import greedy_generate
 
@@ -184,7 +185,11 @@ def serve_preempt(args):
     """The kill → restart serving story, end to end (DESIGN.md §11):
     run the checkpointing ridge demo as a subprocess, SIGTERM it
     ``--preempt-after`` seconds in, restart with ``--resume``, and verify
-    every request still terminates finite with a truthful status."""
+    every request still terminates finite with a truthful status.
+
+    The child needs the accelerator, and a chip belongs to one process:
+    this parent must not initialize a JAX backend (no array, no device
+    query) before ``Popen`` — only config updates happen before it."""
     import os
     import shutil
     import signal
@@ -300,6 +305,7 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main(argv=None):
     args = build_parser().parse_args(argv)
+    enable_compile_cache()
 
     if args.preempt_after:
         return serve_preempt(args)

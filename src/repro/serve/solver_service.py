@@ -588,7 +588,8 @@ class SolverService:
         for i, r in enumerate(reqs):
             ni, di = r.A.shape
             A[i, :ni, :di] = np.asarray(r.A, dtype)
-            b[i, :di] = np.asarray(r.A.T @ r.y, dtype)
+            b[i, :di] = np.asarray(jnp.matmul(
+                r.A.T, r.y, precision=jax.lax.Precision.HIGHEST), dtype)
             nu[i] = r.nu
             if r.lam_diag is not None:
                 lam[i, :di] = np.asarray(r.lam_diag, dtype)

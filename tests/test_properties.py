@@ -42,13 +42,15 @@ def test_fwht_kernel_property(lg_n, d, seed):
     seed=st.integers(min_value=0, max_value=2**30),
 )
 def test_fwht_involution_property(lg_n, d, seed):
-    """H(Hx) = n·x — the Hadamard transform is an involution up to n."""
+    """H(Hx) = n·x — the Hadamard transform is an involution up to n.
+    Entries of H(Hx) are sums of n terms of size ~√n over 2·log₂n fp32
+    stages, so the absolute error grows like ε·n·log₂n."""
     n = 1 << lg_n
     x = jax.random.normal(jax.random.PRNGKey(seed), (n, d))
     hx = fwht(x, axis=0)
     hhx = fwht(hx, axis=0)
     np.testing.assert_allclose(np.asarray(hhx), n * np.asarray(x),
-                               rtol=1e-4, atol=1e-4)
+                               rtol=1e-4, atol=1e-6 * n * lg_n)
 
 
 @settings(max_examples=15, deadline=None)

@@ -73,21 +73,24 @@ def test_block_sketch_gram_scaling_regression():
         for kind in ("gaussian", "sjlt", "srht"):
             f = jax.jit(lambda key: block_sketch_gram(A, key, kind, m, mesh))
             acc = np.zeros((d, d))
+            errs = []
             for r in range(R):
-                SA = np.asarray(f(jax.random.PRNGKey(100 + r)))
-                acc += SA.T @ SA
+                SA = f(jax.random.PRNGKey(100 + r))
+                acc += np.asarray(SA.T @ SA)
+                # unsharded-rate convergence: IHS's fixed 1−ρ step requires
+                # a correctly scaled H_S (pre-fix it diverges to NaN/inf)
+                P = factorize(SA, q.nu, q.lam_diag)
+                x, trace = run_fixed(q, P, jnp.zeros((d,)), method="ihs",
+                                     iters=25, rho=0.5)
+                assert np.isfinite(np.asarray(trace)).all(), (kind, r)
+                errs.append(float(jnp.linalg.norm(x - x_star)
+                                  / jnp.linalg.norm(x_star)))
             rel = np.linalg.norm(acc / R - G) / np.linalg.norm(G)
             assert rel < 0.35, (kind, rel)   # pre-fix: ≈ 0.88
-
-            # unsharded-rate convergence: IHS's fixed 1−ρ step requires a
-            # correctly scaled H_S (pre-fix it diverges to NaN/inf)
-            SA = f(jax.random.PRNGKey(7))
-            P = factorize(SA, q.nu, q.lam_diag)
-            x, trace = run_fixed(q, P, jnp.zeros((d,)), method="ihs",
-                                 iters=25, rho=0.5)
-            err = float(jnp.linalg.norm(x - x_star) / jnp.linalg.norm(x_star))
-            assert np.isfinite(np.asarray(trace)).all(), kind
-            assert err < 1e-3, (kind, err)
+            # at m = 4d an unlucky sketch converges slowly even when
+            # correctly scaled, so the 1e-3 bound is on the median over the
+            # R sketches (pre-fix every solve diverges), not on one seed's
+            assert np.median(errs) < 1e-3, (kind, sorted(errs))
         print("SCALING_OK")
     """)
     assert "SCALING_OK" in out

@@ -13,6 +13,7 @@ from repro.core import (
     direct_solve,
     factorize,
     from_least_squares,
+    gram,
     k_max,
     make_sketch,
     run_fixed,
@@ -23,6 +24,31 @@ from repro.core.effective_dim import m_delta_gaussian
 
 def _rel_err(x, x_star):
     return float(jnp.linalg.norm(x - x_star) / jnp.linalg.norm(x_star))
+
+
+@pytest.mark.parametrize("layout", ["shared", "per_problem",
+                                    "weighted_shared", "weighted"])
+def test_gram_matches_float64(layout):
+    """The engine's chunked, compensated Gram against a float64 AᵀWA for
+    every layout it serves, with n not a multiple of the chunk (the padded
+    tail adds exact zeros)."""
+    B, n, d = 3, 1000, 24
+    kA, kw = jax.random.split(jax.random.PRNGKey(5))
+    shared = layout in ("shared", "weighted_shared")
+    A = jax.random.normal(kA, (n, d) if shared else (B, n, d))
+    w = (jax.random.uniform(kw, (B, n)) if layout.startswith("weighted")
+         else None)
+    G = np.asarray(gram(A, w, chunk=256), np.float64)
+    A64 = np.asarray(A, np.float64)
+    if w is None:
+        ref = (A64.T @ A64 if shared
+               else np.einsum("bnd,bne->bde", A64, A64))
+    else:
+        w64 = np.asarray(w, np.float64)
+        ref = (np.einsum("bn,nd,ne->bde", w64, A64, A64) if shared
+               else np.einsum("bn,bnd,bne->bde", w64, A64, A64))
+    assert G.shape == ref.shape
+    np.testing.assert_allclose(G, ref, rtol=0, atol=1e-5 * np.abs(ref).max())
 
 
 @pytest.mark.parametrize("method", ["ihs", "pcg", "polyak"])
