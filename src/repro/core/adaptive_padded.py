@@ -81,6 +81,12 @@ The full ``PaddedState`` (iterates, best-iterate, per-problem level,
 residual/δ̃ state, counters) is an exported NamedTuple of plain arrays —
 exactly what a checkpoint of a preempted solve persists
 (``core.robust.segmented_padded_solve_batched``).
+
+Profiling (DESIGN.md §14): each solve piece traces under one named scope —
+``engine.sketch``, ``engine.factor``, ``engine.gram``, ``engine.init``,
+``engine.loop``, ``engine.finalize`` — which lands in every HLO op's
+``op_name`` metadata and so in a device trace. A scope changes no jaxpr
+equation, no compiled code and no number.
 """
 
 from __future__ import annotations
@@ -235,6 +241,7 @@ def _valid_level_remap(level_ok: jnp.ndarray):
 # All traceable; the public jitted entry points below compose them.
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("engine.sketch")
 def _compute_ladder_grams(q, keys, *, m_max, sketch, mesh, compute_dtype):
     """(L, B, d, d) ladder-level Grams — the ONE touch of A."""
     provider = get_provider(sketch)
@@ -249,6 +256,7 @@ def _compute_ladder_grams(q, keys, *, m_max, sketch, mesh, compute_dtype):
                              compute_dtype=compute_dtype)
 
 
+@jax.named_scope("engine.factor")
 def _ladder_tables(q: Quadratic, grams: jnp.ndarray, *, guards: bool):
     """Factorize the ladder and build the guard tables from level Grams.
     Returns (pinvs, remap, any_valid, gram_poisoned, invalid_levels);
@@ -283,6 +291,7 @@ def _ladder_tables(q: Quadratic, grams: jnp.ndarray, *, guards: bool):
     return pinvs, remap, any_valid, gram_poisoned, invalid_levels
 
 
+@jax.named_scope("engine.gram")
 def _gram_precompute(q: Quadratic, gram_hvp: bool | None, mesh):
     """The optional true-Gram precompute behind ``gram_hvp`` (None = auto:
     on when d ≤ min(n, 1024)). Returns the (d, d) / (B, d, d) Gram, or
@@ -316,6 +325,7 @@ def _hvp_fn(q: Quadratic, G_full):
         (q.nu**2)[:, None] * q.lam_diag * v)
 
 
+@jax.named_scope("engine.init")
 def _init_padded_state(q: Quadratic, pre: PaddedPrecompute,
                        init_level, tol, x0=None) -> PaddedState:
     B, d = q.batch, q.d
@@ -363,6 +373,7 @@ def _init_padded_state(q: Quadratic, pre: PaddedPrecompute,
     )
 
 
+@jax.named_scope("engine.loop")
 def _run_segment(q: Quadratic, pre: PaddedPrecompute, st: PaddedState,
                  trip_limit, *, method: str, max_iters: int, rho: float,
                  tol, guards: bool) -> PaddedState:
@@ -510,6 +521,7 @@ def _run_segment(q: Quadratic, pre: PaddedPrecompute, st: PaddedState,
     return jax.lax.while_loop(cond, body, st)
 
 
+@jax.named_scope("engine.finalize")
 def _finalize(pre: PaddedPrecompute, st: PaddedState, *, m_max: int):
     """Status lattice + certificates from the terminal (or paused) state."""
     ladder_m = jnp.asarray(doubling_ladder(m_max), jnp.int32)
@@ -663,33 +675,36 @@ def reprecondition_padded(
     # validity composes: a problem frozen by the OLD ladder never iterated
     # (and must stay LEVEL_INVALID); one with no valid level in the NEW
     # ladder freezes now at its best finite iterate
-    any_valid = pre.any_valid & any_valid2
-    pre2 = PaddedPrecompute(
-        pinvs=pinvs, remap=remap, any_valid=any_valid,
-        gram_poisoned=pre.gram_poisoned | gram_poisoned2,
-        invalid_levels=jnp.maximum(pre.invalid_levels, invalid2),
-        G_full=pre.G_full)
-    active = ~st.done
-    pinv_new = _gather_pinv(pinvs, st.level)
-    res = -st.grad                                 # b − Hx at the current x
-    rt = _apply_pinv(pinv_new, res)
-    dt = 0.5 * _pdot(res, rt)
-    dt0 = 0.5 * _pdot(q.b, _apply_pinv(pinv_new, q.b))
-    aB = active[:, None]
-    st2 = st._replace(
-        pinv=jnp.where(active[:, None, None], pinv_new, st.pinv),
-        r=jnp.where(aB, res, st.r),
-        rt=jnp.where(aB, rt, st.rt),
-        p=jnp.where(aB, rt, st.p),
-        x_prev=jnp.where(aB, st.x, st.x_prev),     # momentum restart
-        t_rel=jnp.where(active, 0, st.t_rel),
-        x_best=jnp.where(aB, st.x, st.x_best),
-        dt_best=jnp.where(active, dt, st.dt_best),
-        dtilde_I=jnp.where(active, dt, st.dtilde_I),
-        dtilde=jnp.where(active, dt, st.dtilde),
-        dtilde0=jnp.where(active, dt0, st.dtilde0),
-        done=st.done | (active & ~any_valid),
-    )
+    with jax.named_scope("engine.factor"):
+        any_valid = pre.any_valid & any_valid2
+        pre2 = PaddedPrecompute(
+            pinvs=pinvs, remap=remap, any_valid=any_valid,
+            gram_poisoned=pre.gram_poisoned | gram_poisoned2,
+            invalid_levels=jnp.maximum(pre.invalid_levels, invalid2),
+            G_full=pre.G_full)
+    # the re-anchor is an initialization in the new metric
+    with jax.named_scope("engine.init"):
+        active = ~st.done
+        pinv_new = _gather_pinv(pinvs, st.level)
+        res = -st.grad                         # b − Hx at the current x
+        rt = _apply_pinv(pinv_new, res)
+        dt = 0.5 * _pdot(res, rt)
+        dt0 = 0.5 * _pdot(q.b, _apply_pinv(pinv_new, q.b))
+        aB = active[:, None]
+        st2 = st._replace(
+            pinv=jnp.where(active[:, None, None], pinv_new, st.pinv),
+            r=jnp.where(aB, res, st.r),
+            rt=jnp.where(aB, rt, st.rt),
+            p=jnp.where(aB, rt, st.p),
+            x_prev=jnp.where(aB, st.x, st.x_prev),  # momentum restart
+            t_rel=jnp.where(active, 0, st.t_rel),
+            x_best=jnp.where(aB, st.x, st.x_best),
+            dt_best=jnp.where(active, dt, st.dt_best),
+            dtilde_I=jnp.where(active, dt, st.dtilde_I),
+            dtilde=jnp.where(active, dt, st.dtilde),
+            dtilde0=jnp.where(active, dt0, st.dtilde0),
+            done=st.done | (active & ~any_valid),
+        )
     return pre2, st2
 
 
@@ -948,32 +963,41 @@ def padded_adaptive_solve(
     """Adaptive solve of one problem as a B=1 (or B=c for matrix RHS) batch
     through the padded multi-problem engine. Returns (x, stats) with scalar
     stats for vector right-hand sides; a (d, c) matrix RHS is dispatched as
-    a shared-A batch over columns and gets per-column stats."""
+    a shared-A batch over columns and gets per-column stats.
+
+    The host work around the engine call runs under three profiler spans,
+    ``repro.solve.prepare`` (the batch-of-one views), ``.dispatch`` (the
+    jitted engine call) and ``.unpack`` (x and the stats); with no
+    profiler active each costs under a microsecond (DESIGN.md §14)."""
     if q.batched:
         return padded_adaptive_solve_batched(
             q, key, m_max=m_max, method=method, sketch=sketch,
             max_iters=max_iters, rho=rho, tol=tol,
             compute_dtype=compute_dtype)
-    matrix_rhs = q.b.ndim == 2
-    if matrix_rhs:
-        B = q.b.shape[1]
-        b = q.b.T
-        keys = jax.random.split(key, B)
-    else:
-        B = 1
-        b = q.b[None, :]
-        keys = key[None] if _is_single_key(key) else key
-    nu = jnp.broadcast_to(jnp.atleast_1d(q.nu), (B,))
-    lam = jnp.broadcast_to(q.lam_diag, (B, q.d))
-    w = (None if q.row_weights is None
-         else jnp.broadcast_to(q.row_weights, (B, q.n)))
-    qb = Quadratic(A=q.A, b=b, nu=nu, lam_diag=lam, batched=True,
-                   row_weights=w)
-    x, stats = padded_adaptive_solve_batched(
-        qb, keys, m_max=m_max, method=method, sketch=sketch,
-        max_iters=max_iters, rho=rho, tol=tol,
-        compute_dtype=compute_dtype)
-    if matrix_rhs:
-        return x.T, stats
-    return x[0], {k: (v[0] if getattr(v, "ndim", 0) else v)
-                  for k, v in stats.items()}
+    span = jax.profiler.TraceAnnotation
+    with span("repro.solve.prepare"):
+        matrix_rhs = q.b.ndim == 2
+        if matrix_rhs:
+            B = q.b.shape[1]
+            b = q.b.T
+            keys = jax.random.split(key, B)
+        else:
+            B = 1
+            b = q.b[None, :]
+            keys = key[None] if _is_single_key(key) else key
+        nu = jnp.broadcast_to(jnp.atleast_1d(q.nu), (B,))
+        lam = jnp.broadcast_to(q.lam_diag, (B, q.d))
+        w = (None if q.row_weights is None
+             else jnp.broadcast_to(q.row_weights, (B, q.n)))
+        qb = Quadratic(A=q.A, b=b, nu=nu, lam_diag=lam, batched=True,
+                       row_weights=w)
+    with span("repro.solve.dispatch"):
+        x, stats = padded_adaptive_solve_batched(
+            qb, keys, m_max=m_max, method=method, sketch=sketch,
+            max_iters=max_iters, rho=rho, tol=tol,
+            compute_dtype=compute_dtype)
+    with span("repro.solve.unpack"):
+        if matrix_rhs:
+            return x.T, stats
+        return x[0], {k: (v[0] if getattr(v, "ndim", 0) else v)
+                      for k, v in stats.items()}
