@@ -44,20 +44,35 @@ DESIGN.md §8): the sketch pass embeds W^{1/2}A in the same one touch of A
 and the doubling ladder resumes where the previous Newton step left it.
 
 Cost model: m_t only ever visits the doubling ladder {1, 2, 4, …, m_max},
-so the sketched Gram (SA)ᵀ(SA) is PRECOMPUTED at every ladder level before
-the loop starts, by the family's provider, touching A exactly ONCE —
+so the preconditioner of every ladder level is PRECOMPUTED before the loop
+starts, from one pass of the family's provider touching A exactly ONCE —
 matching the paper's O(sketch) + Σ O(factorize) accounting. The sketch
 pass *streams* A: the Gaussian family fuses row generation with the A
 contraction (``kernels.gaussian_gram`` on TPU, a chunked ``lax.scan``
 elsewhere) so S never exists in HBM; the SJLT routes one dispatch through
 the Pallas MXU kernel and folds the ladder down; the SRHT pays one FWHT.
 Precompute live memory is O(B·m_max·d) row streams + O(B·d²·L) level Grams
-— never O(B·m_max·n). The in-loop refactorization is only a (B,) gather of
-precomputed level inverses, and H_S is factorized in the primal (d×d) form
-for every m_t (ν²Λ ≻ 0 keeps it SPD below d). In exchange for the padded
-d×d factor there is exactly ONE executable and no host round-trips — the
-right trade on real TPU pods where launch latency and recompiles dominate
-at small m.
+in the primal form, O(B·m_max·d·L) in the dual form — never O(B·m_max·n). The in-loop refactorization is only a (B,) gather of
+precomputed per-level tables. There is exactly ONE executable and no host
+round-trips — the right trade on real TPU pods where launch latency and
+recompiles dominate at small m.
+
+Two forms of the ladder (DESIGN.md §6), picked at trace time from what the
+call shows:
+
+* **dual** — every level has m < d (m_max < d), the family's levels are
+  prefixes of one row stream R (``level_rows``: ``gaussian``,
+  ``gaussian_dense``, ``srht``), and the call has no ``mesh`` and no
+  ``grams=``. With D = ν²Λ and s = D^{-1/2}, level l factors the m_l×m_l
+  m_l·I + (R_l·s)(R_l·s)ᵀ = L_lL_lᵀ and keeps U_l = L_l⁻¹(R_l·s), m_l×d
+  (``precond.shifted_ladder_dual``); the loop applies
+  H_S⁻¹z = s ⊙ (y − U_lᵀU_l y), y = s ⊙ z. No d×d matrix is formed.
+* **primal** — every other call: the (L, B, d, d) level Grams and their
+  explicit inverses (``precond.shifted_ladder_inverses``; ν²Λ ≻ 0 keeps
+  H_S SPD below d). The sharded pass psums d×d Grams, the ``sjlt``'s
+  levels are folds rather than prefixes, and path mode hands in Grams.
+
+The stats entry ``ladder_dual`` says which form ran.
 
 Sharding: ``mesh=`` row-shards A over the mesh's data axes and swaps ONLY
 the precompute for the sharded one-touch pass (each shard runs its
@@ -102,7 +117,7 @@ import jax.numpy as jnp
 from repro.kernels.precision import canonical_compute_dtype, fp32_contractions
 
 from .level_grams import get_provider
-from .precond import shifted_ladder_inverses
+from .precond import shifted_ladder_dual, shifted_ladder_inverses
 from .quadratic import Quadratic, gram
 from .solvers import c_alpha_rho, rho_to_rate
 from .status import SolveStatus
@@ -124,7 +139,8 @@ class PaddedState(NamedTuple):
     dtilde0: jnp.ndarray      # (B,)  δ̃ at x₀ under the current sketch
     x_best: jnp.ndarray       # (B, d) best iterate under the current metric
     dt_best: jnp.ndarray      # (B,)  its δ̃ (the returned certificate)
-    pinv: jnp.ndarray         # (B, d, d) gathered H_S⁻¹ at the current level
+    pinv: jnp.ndarray         # (B, d, d) gathered H_S⁻¹ at the current
+                              # level; (B, m_max, d) U_l in the dual form
     iters: jnp.ndarray        # (B,)  accepted iterations
     doublings: jnp.ndarray    # (B,)
     done: jnp.ndarray         # (B,)  bool
@@ -141,7 +157,8 @@ class PaddedPrecompute(NamedTuple):
     ``reprecondition_padded`` (elastic shard recovery, DESIGN.md §11).
     A pytree of plain arrays — deterministic given (q, keys), so a resumed
     process recomputes it instead of checkpointing O(L·B·d²) bytes."""
-    pinvs: jnp.ndarray           # (L, B, d, d) remapped per-level H_S⁻¹
+    pinvs: jnp.ndarray           # (L, B, d, d) remapped per-level H_S⁻¹;
+                                 # (L, B, m_max, d) U_l in the dual form
     remap: jnp.ndarray           # (L, B) valid-level redirect (identity
                                  # when guards are off); −1 ⇒ none valid
     any_valid: jnp.ndarray       # (B,)  problem has ≥1 usable ladder level
@@ -149,11 +166,18 @@ class PaddedPrecompute(NamedTuple):
     invalid_levels: jnp.ndarray  # (B,)  count of skipped ladder levels
     G_full: jnp.ndarray | None   # precomputed AᵀA / AᵀWA ((d, d) shared or
                                  # (B, d, d)); None ⇒ matrix-free hvp
+    dscale: jnp.ndarray | None = None  # (B, d) D^{-1/2}; None ⇒ primal
 
 
-def _apply_pinv(pinv, z):
-    """H_S⁻¹ z as one fused batched matvec — the in-loop hot path."""
-    return jnp.einsum("bde,be->bd", pinv, z)
+def _apply_pinv(pre: PaddedPrecompute, pinv, z):
+    """H_S⁻¹ z at the gathered level — the in-loop hot path: one fused
+    batched matvec in the primal form; s ⊙ (y − U_lᵀ(U_l y)), y = s ⊙ z,
+    in the dual form (U_l's zero rows past m_l add nothing)."""
+    if pre.dscale is None:
+        return jnp.einsum("bde,be->bd", pinv, z)
+    y = pre.dscale * z
+    t = jnp.einsum("bmd,bd->bm", pinv, y)
+    return pre.dscale * (y - jnp.einsum("bmd,bm->bd", pinv, t))
 
 
 def _pdot(a, b):
@@ -189,22 +213,12 @@ def _field_dtype(q: Quadratic):
     return q.A.dtype if q.A.dtype != jnp.int8 else jnp.float32
 
 
-def _precompute_pinvs(grams: jnp.ndarray, q: Quadratic) -> jnp.ndarray:
-    """(L, B, d, d) explicit H_S⁻¹ at EVERY ladder level, as one flattened
-    batched Cholesky + triangular inverse before the loop starts.
-
-    With the inverses precomputed, the in-loop "refactorization" on a
-    doubling is a pure (B,) gather and the per-iteration preconditioner
-    application is one fused batched matvec — no LAPACK dispatch anywhere
-    inside the while_loop. The extra work vs factorizing on demand is at
-    most the ladder length × a d×d Cholesky, a rounding error next to the
-    sketch pass; the forward error of an explicit inverse is the same
-    O(ε·κ) as triangular solves, which a *preconditioner* tolerates.
-
-    The Grams themselves are λ-FREE — the ν²Λ shift enters only inside
-    ``precond.shifted_ladder_inverses`` — which is what lets a
-    regularization path reuse one ladder across every λ (DESIGN.md §13)."""
-    return shifted_ladder_inverses(grams, q.nu, q.lam_diag)
+def _dual_form(q: Quadratic, provider, *, m_max: int, mesh, grams) -> bool:
+    """Static choice of the ladder's form (module docstring): dual when
+    every level has m < d, the levels are prefixes of one row stream, and
+    the call has neither a mesh nor precomputed Grams."""
+    return (grams is None and mesh is None and m_max < q.d
+            and hasattr(provider, "level_rows"))
 
 
 def _gather_pinv(pinvs: jnp.ndarray, level: jnp.ndarray) -> jnp.ndarray:
@@ -242,6 +256,15 @@ def _valid_level_remap(level_ok: jnp.ndarray):
 # ---------------------------------------------------------------------------
 
 @jax.named_scope("engine.sketch")
+def _compute_ladder_rows(q, keys, provider, *, m_max, compute_dtype):
+    """(B, m_max, d) fp32 row stream of a prefix family — the ONE touch of
+    A in the dual form."""
+    data = provider.sample(keys, m_max, q.n, _field_dtype(q))
+    R = provider.level_rows(data, q, m_max, compute_dtype=compute_dtype)
+    return R.astype(jnp.promote_types(R.dtype, jnp.float32))
+
+
+@jax.named_scope("engine.sketch")
 def _compute_ladder_grams(q, keys, *, m_max, sketch, mesh, compute_dtype):
     """(L, B, d, d) ladder-level Grams — the ONE touch of A."""
     provider = get_provider(sketch)
@@ -256,39 +279,103 @@ def _compute_ladder_grams(q, keys, *, m_max, sketch, mesh, compute_dtype):
                              compute_dtype=compute_dtype)
 
 
-@jax.named_scope("engine.factor")
-def _ladder_tables(q: Quadratic, grams: jnp.ndarray, *, guards: bool):
-    """Factorize the ladder and build the guard tables from level Grams.
-    Returns (pinvs, remap, any_valid, gram_poisoned, invalid_levels);
-    with ``guards=False`` the remap is the identity and validity is
-    assumed (the pre-guard hot path, byte-identical gathers)."""
-    B = q.batch
-    pinvs = _precompute_pinvs(grams, q)
+def _unguarded_tables(pinvs: jnp.ndarray, B: int,
+                      **dual) -> PaddedPrecompute:
+    """``guards=False``: the identity remap and every level assumed valid
+    (the pre-guard hot path, byte-identical gathers)."""
     L = pinvs.shape[0]
-    if not guards:
-        remap = jnp.broadcast_to(
-            jnp.arange(L, dtype=jnp.int32)[:, None], (L, B))
-        return (pinvs, remap, jnp.ones((B,), bool),
-                jnp.zeros((B,), bool), jnp.zeros((B,), jnp.int32))
-    # Post-Cholesky validity: a level is usable only if its Gram and its
-    # factorized inverse are entirely finite. Invalid levels are skipped
-    # via the remap (gathers below go through the redirected table);
-    # problems with NO valid level get identity "inverses" so their lanes
-    # stay finite — they are frozen at x₀ before the loop and reported
-    # LEVEL_INVALID.
-    gram_ok = jnp.all(jnp.isfinite(grams), axis=(-1, -2))           # (L, B)
-    level_ok = gram_ok & jnp.all(jnp.isfinite(pinvs), axis=(-1, -2))
-    # non-finite Grams mean poisoned data or a poisoned sketch pass —
-    # distinguishes NAN_POISONED from the finite-but-singular
-    # LEVEL_INVALID verdict when the whole ladder is unusable
+    remap = jnp.broadcast_to(jnp.arange(L, dtype=jnp.int32)[:, None], (L, B))
+    return PaddedPrecompute(
+        pinvs=pinvs, remap=remap, any_valid=jnp.ones((B,), bool),
+        gram_poisoned=jnp.zeros((B,), bool),
+        invalid_levels=jnp.zeros((B,), jnp.int32), G_full=None, **dual)
+
+
+def _guard_tables(pinvs, gram_ok, level_ok, fallback) -> PaddedPrecompute:
+    """Post-Cholesky validity: a level is usable only if its Gram and its
+    factorized table are entirely finite. Invalid levels are skipped via
+    the remap (gathers go through the redirected table); problems with NO
+    valid level get the table ``fallback`` so their lanes stay finite —
+    they are frozen at x₀ before the loop and reported LEVEL_INVALID.
+    Non-finite Grams mean poisoned data or a poisoned sketch pass, which
+    distinguishes NAN_POISONED from the finite-but-singular LEVEL_INVALID
+    verdict when the whole ladder is unusable."""
     gram_poisoned = jnp.any(~gram_ok, axis=0)                       # (B,)
     remap, any_valid = _valid_level_remap(level_ok)
     pinvs = jnp.take_along_axis(
         pinvs, jnp.maximum(remap, 0)[:, :, None, None], axis=0)
-    pinvs = jnp.where(any_valid[None, :, None, None], pinvs,
-                      jnp.eye(q.d, dtype=pinvs.dtype))
+    pinvs = jnp.where(any_valid[None, :, None, None], pinvs, fallback)
     invalid_levels = jnp.sum(~level_ok, axis=0).astype(jnp.int32)
-    return pinvs, remap, any_valid, gram_poisoned, invalid_levels
+    return PaddedPrecompute(
+        pinvs=pinvs, remap=remap, any_valid=any_valid,
+        gram_poisoned=gram_poisoned, invalid_levels=invalid_levels,
+        G_full=None)
+
+
+@jax.named_scope("engine.factor")
+def _ladder_tables(q: Quadratic, grams: jnp.ndarray, *,
+                   guards: bool) -> PaddedPrecompute:
+    """Primal form: the (L, B, d, d) explicit H_S⁻¹ at every ladder level,
+    from the λ-free level Grams (the ν²Λ shift enters only inside
+    ``precond.shifted_ladder_inverses``, which is what lets a
+    regularization path reuse one ladder across every λ, DESIGN.md §13),
+    and the guard tables. ``G_full`` is left None for the caller.
+
+    With the inverses precomputed, the in-loop "refactorization" on a
+    doubling is a pure (B,) gather and the per-iteration preconditioner
+    application one fused batched matvec — no LAPACK dispatch inside the
+    while_loop; the forward error of an explicit inverse is the same
+    O(ε·κ) as triangular solves, which a *preconditioner* tolerates."""
+    pinvs = shifted_ladder_inverses(grams, q.nu, q.lam_diag)
+    if not guards:
+        return _unguarded_tables(pinvs, q.batch)
+    gram_ok = jnp.all(jnp.isfinite(grams), axis=(-1, -2))           # (L, B)
+    level_ok = gram_ok & jnp.all(jnp.isfinite(pinvs), axis=(-1, -2))
+    return _guard_tables(pinvs, gram_ok, level_ok,
+                         jnp.eye(q.d, dtype=pinvs.dtype))
+
+
+@jax.named_scope("engine.factor")
+def _dual_ladder_tables(q: Quadratic, rows: jnp.ndarray, *, m_max: int,
+                        guards: bool) -> PaddedPrecompute:
+    """Dual form: the (L, B, m_max, d) U_l of every ladder level
+    (``precond.shifted_ladder_dual``) and the guard tables, with the same
+    meaning as the primal form's: a level's Gram is finite when its prefix
+    of R is, and the level is valid when its U_l is finite too. Level l
+    reads only the first m_l rows, so a non-finite row past them touches
+    nothing of it. A problem with no valid level gets U = 0 and
+    D^{-1/2} = 1 — the identity, as in the primal form."""
+    ladder = doubling_ladder(m_max)
+    pinvs, dscale = shifted_ladder_dual(rows, ladder, q.nu, q.lam_diag)
+    if not guards:
+        return _unguarded_tables(pinvs, q.batch, dscale=dscale)
+    row_ok = jnp.all(jnp.isfinite(rows), axis=-1)                   # (B, M)
+    gram_ok = jnp.stack([jnp.all(row_ok[:, :m], axis=-1) for m in ladder])
+    level_ok = gram_ok & jnp.all(jnp.isfinite(pinvs), axis=(-1, -2))
+    pre = _guard_tables(pinvs, gram_ok, level_ok, jnp.zeros((), pinvs.dtype))
+    return pre._replace(
+        dscale=jnp.where(pre.any_valid[:, None], dscale, 1.0))
+
+
+def _ladder_precompute(q: Quadratic, keys, *, m_max, sketch, mesh, guards,
+                       compute_dtype, grams, gram_hvp,
+                       gram_full) -> PaddedPrecompute:
+    """The sketch pass (unless ``grams`` are given), the ladder tables in
+    the form ``_dual_form`` picks, and the optional true Gram."""
+    provider = get_provider(sketch)
+    if _dual_form(q, provider, m_max=m_max, mesh=mesh, grams=grams):
+        rows = _compute_ladder_rows(q, keys, provider, m_max=m_max,
+                                    compute_dtype=compute_dtype)
+        pre = _dual_ladder_tables(q, rows, m_max=m_max, guards=guards)
+    else:
+        if grams is None:
+            grams = _compute_ladder_grams(q, keys, m_max=m_max,
+                                          sketch=sketch, mesh=mesh,
+                                          compute_dtype=compute_dtype)
+        pre = _ladder_tables(q, grams, guards=guards)
+    if gram_full is None:
+        gram_full = _gram_precompute(q, gram_hvp, mesh)
+    return pre._replace(G_full=gram_full)
 
 
 @jax.named_scope("engine.gram")
@@ -341,7 +428,7 @@ def _init_padded_state(q: Quadratic, pre: PaddedPrecompute,
     if x0 is None:
         x0 = jnp.zeros((B, d), fdtype)
         g0 = grad_f(x0)                              # = −b
-        rt0 = _apply_pinv(pinv0, -g0)
+        rt0 = _apply_pinv(pre, pinv0, -g0)
         dtw = 0.5 * _pdot(-g0, rt0)
         dt0 = dtw
         conv0 = dt0 <= tol * dt0                     # trivially-solved (b=0)
@@ -354,9 +441,9 @@ def _init_padded_state(q: Quadratic, pre: PaddedPrecompute,
         # converges before the loop runs a single trip.
         x0 = x0.astype(fdtype)
         g0 = grad_f(x0)
-        rt0 = _apply_pinv(pinv0, -g0)
+        rt0 = _apply_pinv(pre, pinv0, -g0)
         dtw = 0.5 * _pdot(-g0, rt0)
-        dt0 = 0.5 * _pdot(q.b, _apply_pinv(pinv0, q.b))
+        dt0 = 0.5 * _pdot(q.b, _apply_pinv(pre, pinv0, q.b))
         conv0 = dtw <= tol * dt0
 
     return PaddedState(
@@ -413,7 +500,7 @@ def _run_segment(q: Quadratic, pre: PaddedPrecompute, st: PaddedState,
             else:
                 x_new = st.x + mu_p * st.rt + beta_p * (st.x - st.x_prev)
             g_new = grad_f(x_new)
-            rt_new = _apply_pinv(pinv, -g_new)
+            rt_new = _apply_pinv(pre, pinv, -g_new)
             dt_new = 0.5 * _pdot(-g_new, rt_new)
             r_new, p_new = -g_new, st.p
         else:  # pcg
@@ -423,7 +510,7 @@ def _run_segment(q: Quadratic, pre: PaddedPrecompute, st: PaddedState,
             alpha_s = jnp.where(ok, 2.0 * st.dtilde / jnp.where(ok, denom, 1.0), 0.0)
             x_new = st.x + alpha_s[:, None] * st.p
             r_new = st.r - alpha_s[:, None] * Hp
-            rt_new = _apply_pinv(pinv, r_new)
+            rt_new = _apply_pinv(pre, pinv, r_new)
             dt_new = 0.5 * _pdot(r_new, rt_new)
             okb = st.dtilde > 0
             beta = jnp.where(okb, dt_new / jnp.where(okb, st.dtilde, 1.0), 0.0)
@@ -496,9 +583,9 @@ def _run_segment(q: Quadratic, pre: PaddedPrecompute, st: PaddedState,
             # extra H·v is needed.
             pinv_new = _gather_pinv(pre.pinvs, s.level)
             res = -s.grad                              # b − Hx at current x
-            rt_re = _apply_pinv(pinv_new, res)
+            rt_re = _apply_pinv(pre, pinv_new, res)
             dt_re = 0.5 * _pdot(res, rt_re)
-            dt0_re = 0.5 * _pdot(q.b, _apply_pinv(pinv_new, q.b))
+            dt0_re = 0.5 * _pdot(q.b, _apply_pinv(pre, pinv_new, q.b))
             rB = reject[:, None]
             return s._replace(
                 pinv=pinv_new,
@@ -543,7 +630,8 @@ def _finalize(pre: PaddedPrecompute, st: PaddedState, *, m_max: int):
              "level": eff_level, "trips": st.trips,
              "status": status, "converged": st.converged,
              "stalled": status == jnp.int32(SolveStatus.STALLED),
-             "invalid_levels": pre.invalid_levels}
+             "invalid_levels": pre.invalid_levels,
+             "ladder_dual": jnp.full((B,), pre.dscale is not None)}
     return st.x_best, stats
 
 
@@ -586,18 +674,10 @@ def prepare_padded_solve(
     B = q.batch
     if _is_single_key(keys):
         keys = jax.random.split(keys, B)
-    compute_dtype = canonical_compute_dtype(compute_dtype)
-    if grams is None:
-        grams = _compute_ladder_grams(q, keys, m_max=m_max, sketch=sketch,
-                                      mesh=mesh, compute_dtype=compute_dtype)
-    pinvs, remap, any_valid, gram_poisoned, invalid_levels = _ladder_tables(
-        q, grams, guards=guards)
-    if gram_full is None:
-        gram_full = _gram_precompute(q, gram_hvp, mesh)
-    pre = PaddedPrecompute(
-        pinvs=pinvs, remap=remap, any_valid=any_valid,
-        gram_poisoned=gram_poisoned, invalid_levels=invalid_levels,
-        G_full=gram_full)
+    pre = _ladder_precompute(
+        q, keys, m_max=m_max, sketch=sketch, mesh=mesh, guards=guards,
+        compute_dtype=canonical_compute_dtype(compute_dtype), grams=grams,
+        gram_hvp=gram_hvp, gram_full=gram_full)
     return pre, _init_padded_state(q, pre, init_level, tol, x0=x0)
 
 
@@ -670,29 +750,32 @@ def reprecondition_padded(
     targets the ORIGINAL problem exactly; only the preconditioner weakens —
     so a subsequent convergence is an honest ``OK`` with a truthful δ̃.
     Problems already done keep their iterates and verdicts bit-for-bit."""
-    pinvs, remap, any_valid2, gram_poisoned2, invalid2 = _ladder_tables(
-        q, grams, guards=guards)
+    new = _ladder_tables(q, grams, guards=guards)
     # validity composes: a problem frozen by the OLD ladder never iterated
     # (and must stay LEVEL_INVALID); one with no valid level in the NEW
     # ladder freezes now at its best finite iterate
     with jax.named_scope("engine.factor"):
-        any_valid = pre.any_valid & any_valid2
-        pre2 = PaddedPrecompute(
-            pinvs=pinvs, remap=remap, any_valid=any_valid,
-            gram_poisoned=pre.gram_poisoned | gram_poisoned2,
-            invalid_levels=jnp.maximum(pre.invalid_levels, invalid2),
+        any_valid = pre.any_valid & new.any_valid
+        pre2 = new._replace(
+            any_valid=any_valid,
+            gram_poisoned=pre.gram_poisoned | new.gram_poisoned,
+            invalid_levels=jnp.maximum(pre.invalid_levels,
+                                       new.invalid_levels),
             G_full=pre.G_full)
     # the re-anchor is an initialization in the new metric
     with jax.named_scope("engine.init"):
         active = ~st.done
-        pinv_new = _gather_pinv(pinvs, st.level)
+        pinv_new = _gather_pinv(pre2.pinvs, st.level)
         res = -st.grad                         # b − Hx at the current x
-        rt = _apply_pinv(pinv_new, res)
+        rt = _apply_pinv(pre2, pinv_new, res)
         dt = 0.5 * _pdot(res, rt)
-        dt0 = 0.5 * _pdot(q.b, _apply_pinv(pinv_new, q.b))
+        dt0 = 0.5 * _pdot(q.b, _apply_pinv(pre2, pinv_new, q.b))
         aB = active[:, None]
+        # the new ladder is primal: a dual-form state's U_l is read by no
+        # lane any more, so every lane takes the d×d inverse
+        keep = st.pinv if st.pinv.shape == pinv_new.shape else pinv_new
         st2 = st._replace(
-            pinv=jnp.where(active[:, None, None], pinv_new, st.pinv),
+            pinv=jnp.where(active[:, None, None], pinv_new, keep),
             r=jnp.where(aB, res, st.r),
             rt=jnp.where(aB, rt, st.rt),
             p=jnp.where(aB, rt, st.p),
@@ -816,18 +899,10 @@ def padded_adaptive_solve_batched(
     B = q.batch
     if _is_single_key(keys):
         keys = jax.random.split(keys, B)
-    compute_dtype = canonical_compute_dtype(compute_dtype)
-    if grams is None:
-        grams = _compute_ladder_grams(q, keys, m_max=m_max, sketch=sketch,
-                                      mesh=mesh, compute_dtype=compute_dtype)
-    pinvs, remap, any_valid, gram_poisoned, invalid_levels = _ladder_tables(
-        q, grams, guards=guards)
-    if gram_full is None:
-        gram_full = _gram_precompute(q, gram_hvp, mesh)
-    pre = PaddedPrecompute(
-        pinvs=pinvs, remap=remap, any_valid=any_valid,
-        gram_poisoned=gram_poisoned, invalid_levels=invalid_levels,
-        G_full=gram_full)
+    pre = _ladder_precompute(
+        q, keys, m_max=m_max, sketch=sketch, mesh=mesh, guards=guards,
+        compute_dtype=canonical_compute_dtype(compute_dtype), grams=grams,
+        gram_hvp=gram_hvp, gram_full=gram_full)
     init = _init_padded_state(q, pre, init_level, tol, x0=x0)
     st = _run_segment(q, pre, init, padded_trip_cap(m_max, max_iters),
                       method=method, max_iters=max_iters, rho=rho, tol=tol,

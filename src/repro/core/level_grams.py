@@ -12,6 +12,14 @@ level — behind one protocol (DESIGN.md §6):
 * ``level_grams(data, q, ladder)`` → (L, B, d, d) Grams, touching A
   exactly ONCE (the paper's O(sketch) + Σ O(factorize) accounting).
 
+The prefix families (``gaussian``, ``gaussian_dense``, ``srht``), whose
+level-m sketch is the first m rows of one row stream, also expose
+``level_rows(data, q, m_max)`` → that (B, m_max, d) stream R, with the
+level-m Gram R[:m]ᵀR[:m]/m; their ``level_grams`` is
+``prefix_level_grams(level_rows(...))``. The engine factors a ladder whose
+levels all have m < d from R itself, in the m×m dual form
+(``core.adaptive_padded``, DESIGN.md §6).
+
 The level Grams are λ-FREE: no provider reads ``q.nu`` / ``q.lam_diag``
 — the ν²Λ shift enters only at factorization
 (``precond.shifted_ladder_inverses``). That is what lets one ladder
@@ -127,12 +135,26 @@ def prefix_level_grams(R: jnp.ndarray, ladder: tuple[int, ...], *,
     return jnp.stack(grams)
 
 
+class _PrefixLevelGrams:
+    """``level_grams`` of a family whose levels are prefixes of one row
+    stream. The family's ``level_rows(data, q, m_max, row_weights=None,
+    compute_dtype=None)`` returns that (B, m_max, d) stream R of
+    S_{m_max} W^{1/2}A without the 1/√m rescale, touching A once; the
+    level-m Gram is R[:, :m]ᵀR[:, :m]/m."""
+
+    def level_grams(self, data, q, ladder, row_weights=None,
+                    compute_dtype=None):
+        R = self.level_rows(data, q, ladder[-1], row_weights=row_weights,
+                            compute_dtype=compute_dtype)
+        return prefix_level_grams(R, ladder, inv_m_scale=True)
+
+
 def _uint32_seeds(keys: jax.Array) -> jnp.ndarray:
     """One uint32 counter-hash seed per problem key."""
     return jax.vmap(lambda k: jax.random.bits(k, dtype=jnp.uint32))(keys)
 
 
-class GaussianStreamedProvider:
+class GaussianStreamedProvider(_PrefixLevelGrams):
     """Streaming fused sketch→Gram (the default ``gaussian`` family)."""
 
     name = "gaussian"
@@ -140,15 +162,14 @@ class GaussianStreamedProvider:
     def sample(self, keys, m_max, n, dtype):
         return {"seeds": _uint32_seeds(keys)}
 
-    def level_grams(self, data, q, ladder, row_weights=None,
-                    compute_dtype=None):
-        SA = ops.gaussian_sa(q.A, data["seeds"], ladder[-1],
-                             row_weights=_weights(q, row_weights),
-                             compute_dtype=compute_dtype)
-        return prefix_level_grams(SA, ladder, inv_m_scale=True)
+    def level_rows(self, data, q, m_max, row_weights=None,
+                   compute_dtype=None):
+        return ops.gaussian_sa(q.A, data["seeds"], m_max,
+                               row_weights=_weights(q, row_weights),
+                               compute_dtype=compute_dtype)
 
 
-class GaussianDenseProvider:
+class GaussianDenseProvider(_PrefixLevelGrams):
     """Materialized-S baseline: identical sketch entries, O(B·m_max·n)."""
 
     name = "gaussian_dense"
@@ -156,9 +177,8 @@ class GaussianDenseProvider:
     def sample(self, keys, m_max, n, dtype):
         return {"seeds": _uint32_seeds(keys)}
 
-    def level_grams(self, data, q, ladder, row_weights=None,
-                    compute_dtype=None):
-        m_max = ladder[-1]
+    def level_rows(self, data, q, m_max, row_weights=None,
+                   compute_dtype=None):
         B = data["seeds"].shape[0]
         # same per-row scale algebra as the streamed provider: w^{1/2} and
         # int8 dequantization scales merge into one (B, n) column scale on
@@ -174,7 +194,7 @@ class GaussianDenseProvider:
         else:
             SA = jnp.einsum("bmn,bnd->bmd", S.astype(ct), A.astype(ct),
                             preferred_element_type=jnp.float32)
-        return prefix_level_grams(SA, ladder, inv_m_scale=True)
+        return SA
 
 
 class SJLTProvider:
@@ -215,7 +235,7 @@ class SJLTProvider:
             [jnp.einsum("bmd,bme->bde", by_m[m], by_m[m]) for m in ladder])
 
 
-class SRHTProvider:
+class SRHTProvider(_PrefixLevelGrams):
     """SRHT ladder: one FWHT pass, level-m = first m of a fixed row stream.
 
     Row-sampling law: rows are i.i.d. uniform over the padded index space
@@ -237,8 +257,8 @@ class SRHTProvider:
             jax.random.fold_in(k, 1), (m_max,), 0, n_pad))(keys)
         return {"signs": signs, "rows": rows}
 
-    def level_grams(self, data, q, ladder, row_weights=None,
-                    compute_dtype=None):
+    def level_rows(self, data, q, m_max, row_weights=None,
+                   compute_dtype=None):
         signs, rows = data["signs"], data["rows"]
         n, d = q.n, q.d
         B = signs.shape[0]
@@ -267,8 +287,7 @@ class SRHTProvider:
             scale = jnp.pad(scale, ((0, 0), (0, n_pad - n)))
         HX = ops.fwht_cols(X, row_scale=scale,             # the ONE touch
                            compute_dtype=compute_dtype)
-        picked = jnp.take_along_axis(HX, rows[:, :, None], axis=1)
-        return prefix_level_grams(picked, ladder, inv_m_scale=True)
+        return jnp.take_along_axis(HX, rows[:, :, None], axis=1)
 
 
 class BlockEmulationProvider:

@@ -19,6 +19,9 @@ idea to the adaptive engine's doubling ladder (DESIGN.md §13): the
 diagonal shift added immediately before the flattened batched Cholesky —
 so ONE one-touch sketch pass serves every λ point of a regularization
 path; only this O(L·B·d³) factorization is repeated per λ.
+``shifted_ladder_dual`` is the same ladder in the dual regime above, for
+ladders whose levels all have m < d and are prefixes of one row stream:
+an m×m factorization per level, O(B·m_max²·d + Σ m³) in all.
 
 The factorization object is a pytree so it can be closed over / donated in
 jitted solver loops.
@@ -225,6 +228,43 @@ def shifted_ladder_inverses(
     y = solve_triangular(chol, eye, lower=True)
     pinv = solve_triangular(jnp.swapaxes(chol, -1, -2), y, lower=False)
     return pinv.reshape(L, B, d, d)
+
+
+def shifted_ladder_dual(
+    rows: jnp.ndarray,
+    ladder: tuple[int, ...],
+    nu: jnp.ndarray,
+    lam_diag: jnp.ndarray,
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """The dual (Woodbury) form of ``shifted_ladder_inverses``, for a ladder
+    whose levels are prefixes of one row stream and all have m < d.
+
+    ``rows`` is the (B, m_max, d) stream R whose level-m Gram is
+    R_mᵀR_m/m (R_m: its first m rows). With D = ν²Λ, s = D^{-1/2} and
+    V_m = R_m·diag(s),
+
+        (R_mᵀR_m/m + D)⁻¹ = diag(s)·(I − U_mᵀU_m)·diag(s),
+        U_m = L_m⁻¹V_m,   L_mL_mᵀ = m·I + V_mV_mᵀ,
+
+    so each level factors an m×m matrix, at its own size: K = V Vᵀ is
+    formed once at HIGHEST and level m Choleskys m·I + K[:m, :m]. U_m has
+    spectral norm below 1 and comes from a backward-stable triangular
+    solve, which keeps the fp32 error of applying I − UᵀU to O(ε‖y‖): the
+    order of the primal explicit inverse's. (Applying (m·I + K)⁻¹ between
+    V and Vᵀ instead loses O(ε·κ²) in the top directions.) Returns
+    ``(U, s)``: the (L, B, m_max, d) table of the U_m, zero-padded below
+    row m, and s as (B, d)."""
+    B, m_max, _ = rows.shape
+    hi = jax.lax.Precision.HIGHEST
+    s = jax.lax.rsqrt((nu**2)[:, None] * lam_diag)              # (B, d)
+    V = rows * s[:, None, :]
+    K = jnp.einsum("bmd,bnd->bmn", V, V, precision=hi)
+    tables = []
+    for m in ladder:
+        chol = jnp.linalg.cholesky(K[:, :m, :m] + m * jnp.eye(m, dtype=K.dtype))
+        U = solve_triangular(chol, V[:, :m], lower=True)
+        tables.append(jnp.pad(U, ((0, 0), (0, m_max - m), (0, 0))))
+    return jnp.stack(tables), s
 
 
 def factorization_cost_flops(m: int, n: int, d: int) -> float:

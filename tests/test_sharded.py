@@ -272,6 +272,18 @@ def test_sharded_engine_matches_single_device():
             assert 0.5 <= ratio <= 2.0, (i, ratio)
         assert np.array_equal(np.asarray(s_sh["m_final"]),
                               np.asarray(s_emu["m_final"]))
+        # at m_max < d one device takes the dual ladder form; the mesh
+        # keeps the primal form, whose psum is over d×d level Grams
+        kw = dict(m_max=8, method="pcg", tol=1e-12, max_iters=200)
+        x_sh, s_sh = sharded_padded_solve(q, keys, mesh, sketch="gaussian",
+                                          **kw)
+        x_1, s_1 = padded_adaptive_solve_batched(q, keys, sketch="gaussian",
+                                                 **kw)
+        assert not np.asarray(s_sh["ladder_dual"]).any()
+        assert np.asarray(s_1["ladder_dual"]).all()
+        for i in range(B):
+            assert rel(x_sh[i], X[i]) <= 1e-4, i
+            assert rel(x_1[i], X[i]) <= 1e-4, i
         print("ENGINE_OK")
     """)
     assert "ENGINE_OK" in out

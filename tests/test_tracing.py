@@ -16,12 +16,16 @@ import pytest
 
 from repro.core import padded_adaptive_solve
 from repro.core.adaptive_padded import (
-    finalize_padded_solve, padded_adaptive_solve_batched,
+    doubling_ladder, finalize_padded_solve, padded_adaptive_solve_batched,
     padded_solve_segment, prepare_padded_solve, prepare_path_ladder,
     reprecondition_padded)
 from repro.core.quadratic import Quadratic
 
-N, D, M_MAX = 256, 32, 16
+N, D = 256, 32
+# m_max < d takes the dual ladder form, m_max = 2d (a service shape class)
+# the primal one (DESIGN.md §6)
+M_DUAL, M_PRIMAL = 16, 64
+M_MAX = M_DUAL
 # the instruction kinds that do a phase's work on a device
 WORK_OPS = ("fusion", "dot", "convolution", "custom-call", "while")
 # work instructions the CPU compiler makes itself, with no metadata: a
@@ -76,11 +80,9 @@ def _scopes_of(hlo: str) -> set[str]:
     return set(_SCOPE.findall(" ".join(_OP_NAME.findall(hlo))))
 
 
-@pytest.mark.parametrize("gram_hvp", [True, False])
-@pytest.mark.parametrize("method", ["ihs", "pcg"])
-def test_every_work_op_carries_one_phase_scope(method, gram_hvp):
+def _assert_one_phase_scope_per_work_op(m_max, method, gram_hvp):
     hlo = padded_adaptive_solve_batched.lower(
-        _problem(True), _keys(), m_max=M_MAX, method=method,
+        _problem(True), _keys(), m_max=m_max, method=method,
         gram_hvp=gram_hvp).compile().as_text()
     by_kind, unnamed = work_scopes(hlo)
     assert {"fusion", "dot", "custom-call", "while"} <= set(by_kind)
@@ -96,6 +98,41 @@ def test_every_work_op_carries_one_phase_scope(method, gram_hvp):
     assert seen == phases | ({"engine.gram"} if gram_hvp else set())
     # the loop's own while op, apart from the sketch pass's chunk scan
     assert ("engine.loop",) in by_kind["while"]
+
+
+@pytest.mark.parametrize("gram_hvp", [True, False])
+@pytest.mark.parametrize("method", ["ihs", "pcg"])
+def test_every_work_op_carries_one_phase_scope(method, gram_hvp):
+    _assert_one_phase_scope_per_work_op(M_DUAL, method, gram_hvp)
+
+
+@pytest.mark.parametrize("gram_hvp", [True, False])
+@pytest.mark.parametrize("method", ["ihs", "pcg"])
+def test_every_work_op_carries_one_phase_scope_in_primal_form(method,
+                                                              gram_hvp):
+    _assert_one_phase_scope_per_work_op(M_PRIMAL, method, gram_hvp)
+
+
+def test_dual_form_ops_are_scoped_and_no_d_by_d_matrix_is_formed():
+    """In the dual form the row stream is the sketch pass's, K, the level
+    Choleskys and the U_l are the factorization's, and no instruction
+    makes a d×d array (the matrix-free hvp forms no Gram either)."""
+    hlo = padded_adaptive_solve_batched.lower(
+        _problem(True), _keys(), m_max=M_DUAL, method="pcg",
+        gram_hvp=False).compile().as_text()
+    assert f"{D},{D}]" not in hlo
+    factor = [line for line in hlo.splitlines()
+              if "/engine.factor/" in line]
+    assert any("cholesky" in line for line in factor)
+    assert any("triangular_solve" in line for line in factor)
+    # K = V Vᵀ: a (1, m_max, m_max) contraction, and the (L, 1, m_max, d)
+    # table of the U_l
+    assert any(f"[1,{M_DUAL},{M_DUAL}]" in line and "dot_general" in line
+               for line in factor)
+    L = len(doubling_ladder(M_DUAL))
+    assert any(f"[{L},1,{M_DUAL},{D}]" in line for line in factor)
+    sketch = [line for line in hlo.splitlines() if "/engine.sketch/" in line]
+    assert any(f"[1,{M_DUAL},{D}]" in line for line in sketch)
 
 
 def test_segment_pieces_carry_their_scopes():
